@@ -60,6 +60,9 @@ def run() -> list[tuple[str, float, str]]:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # the child measures the CPU on purpose; pinning it keeps the child off
+    # an accelerator this process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _MEASURE], env=env,
                           capture_output=True, text=True, timeout=560)
     if proc.returncode == 0:

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # the 512 devices are host devices, never an accelerator
 
 DOC = """§Perf hillclimb driver — hypothesis → change → re-lower → re-analyse.
 

@@ -190,7 +190,6 @@ class CompiledPlan:
             return self._run_jax(inputs, axis_name=axis_name, item_dtype=item_dtype)
 
     def _run_jax(self, inputs, *, axis_name: str, item_dtype):
-        import repro._jax_compat  # noqa: F401  (shims before any jax use)
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P

@@ -781,7 +781,6 @@ def _make_jax_step(esw, up, lvl, ns, maxlvl):
     port caps, no finite buffers. Returns None when jax is unavailable
     so the numpy baseline silently takes over."""
     try:
-        import repro._jax_compat  # noqa: F401
         import jax
         import jax.numpy as jnp
     except Exception:  # pragma: no cover - jax baked into the image

@@ -10,8 +10,6 @@ built from:
     serialization.*                  — §3 cost model (r = C/e) + chunk model
     compile_source / compile_program / wordcount_step — deprecated shims
 """
-import repro._jax_compat  # noqa: F401  (installs old-jax API shims)
-
 from repro.core import collectives, primitives, serialization
 from repro.core.codelet import compile_program, execute_reference
 from repro.core.dag import Program, ProgramError, paper_example

@@ -54,7 +54,7 @@ def pipeline_apply(
         emit = jnp.where((s == p - 1) & active, y, jnp.zeros_like(y))
         return handoff, emit
 
-    init = lax.pvary(jnp.zeros_like(microbatches[0]), (axis_name,))
+    init = lax.pcast(jnp.zeros_like(microbatches[0]), axis_name, to="varying")
     _, emitted = lax.scan(tick, init, jnp.arange(ticks))
     # micro m exits at tick m + p - 1: compact (ticks, ...) -> (n, ...)
     out = emitted[p - 1:]

@@ -26,8 +26,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k), slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k), slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (bq, bk)
         if causal:

@@ -4,8 +4,6 @@ from __future__ import annotations
 
 import jax
 
-import repro._jax_compat  # noqa: F401  (installs old-jax API shims)
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 v5e pod slice, or 2 pods = 512 chips with a leading DCN axis."""

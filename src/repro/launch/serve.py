@@ -34,9 +34,11 @@ def run(args):
     import jax.numpy as jnp
     from repro.configs import get_config, get_smoke_config
     from repro.launch import steps as steps_lib
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.models.common import init_params
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_mesh(tuple(int(x) for x in args.mesh.split(",")))
     total = args.prompt_len + args.gen

@@ -63,8 +63,11 @@ def run(args):
     import jax
     from repro.checkpoint.store import CheckpointStore
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.runtime.fault_tolerance import elastic_mesh_plan
+
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if args.moe_dispatch:

@@ -18,8 +18,11 @@ own ``all_to_all`` anymore:
   bucket, padding slots carry -1.
 
 All functions must run inside ``shard_map`` over ``axis_name``. The
-token path runs the Pallas kernel inside shard_map, which on jax 0.4.x
-needs ``check_rep=False`` (pallas_call has no replication rule).
+token path runs the Pallas kernel inside shard_map; the kernel states
+that its outputs vary over the same mesh axes as its input, so the
+compiled kernel passes ``check_vma``. In interpret mode the Pallas
+interpreter runs the kernel body without varying-axis casts, and the
+caller's ``shard_map`` needs ``check_vma=False``.
 """
 from __future__ import annotations
 

@@ -8,72 +8,12 @@ jax — see ``run_multidevice``.
 """
 from __future__ import annotations
 
-import functools
 import os
 import subprocess
 import sys
 import textwrap
-import types
 
 import pytest
-
-# ---------------------------------------------------------------------------
-# hypothesis guard: the property tests are optional. When hypothesis is not
-# installed we install a stub module whose @given replaces the test body with
-# a pytest.skip, so every non-property test in the same module still runs
-# (a module-level importorskip would skip whole files).
-# ---------------------------------------------------------------------------
-try:  # pragma: no cover - exercised implicitly by the suite
-    import hypothesis  # noqa: F401
-
-    HAVE_HYPOTHESIS = True
-except ModuleNotFoundError:
-    HAVE_HYPOTHESIS = False
-
-    def _given(*_args, **_kwargs):
-        def deco(fn):
-            @functools.wraps(fn)
-            def skipper():
-                pytest.skip("hypothesis not installed")
-
-            # pytest introspects __wrapped__ for the signature; drop it so the
-            # skipper presents zero parameters (no fixture lookup for strategy
-            # arguments).
-            del skipper.__wrapped__
-            return skipper
-
-        return deco
-
-    def _settings(*_args, **_kwargs):
-        return lambda fn: fn
-
-    _settings.register_profile = lambda *a, **k: None
-    _settings.load_profile = lambda *a, **k: None
-
-    class _StrategiesStub(types.ModuleType):
-        """st.<anything>(...) returns an opaque placeholder; st.composite
-        returns a builder so module-level ``programs()`` calls succeed."""
-
-        @staticmethod
-        def composite(fn):
-            return lambda *a, **k: None
-
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    _st = _StrategiesStub("hypothesis.strategies")
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.IS_STUB = True  # lets tests mark property cases skipped explicitly
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.assume = lambda *a, **k: True
-    _hyp.note = lambda *a, **k: None
-    _hyp.HealthCheck = types.SimpleNamespace(
-        too_slow=None, data_too_large=None, filter_too_much=None
-    )
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
@@ -84,7 +24,6 @@ def run_multidevice(code: str, n_devices: int = 8, timeout: int = 480) -> str:
     prelude = (
         "import os\n"
         f"os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count={n_devices}'\n"
-        "import repro._jax_compat\n"  # old-jax API shims before any jax use
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")

@@ -3,16 +3,9 @@ import json
 
 import pytest
 
-# Optional dependency: when hypothesis is absent, conftest installs a stub so
-# this import succeeds and only the property tests below are skipped.
-import hypothesis
 from hypothesis import given, settings, strategies as st
 
 from repro.core import dag, dsl, primitives as prim
-
-requires_hypothesis = pytest.mark.skipif(
-    getattr(hypothesis, "IS_STUB", False), reason="hypothesis not installed"
-)
 
 
 def test_paper_source_parses_to_expected_ast():
@@ -102,7 +95,6 @@ def programs(draw):
     return p
 
 
-@requires_hypothesis
 @given(programs())
 @settings(max_examples=60, deadline=None)
 def test_random_programs_valid(p):

@@ -37,10 +37,11 @@ def test_hash_partition_property(n, buckets):
 
 @pytest.mark.parametrize("n,buckets", [
     (1, 2),        # single token
-    (1023, 8),     # one short of the block size
-    (1024, 8),     # exactly one block
-    (1025, 8),     # one into the second block (kernel pads with -1)
-    (3000, 16),    # multi-block, ragged tail
+    (1023, 8),     # one short of the smallest (8 x 128) tile
+    (1024, 8),     # exactly one tile
+    (1025, 8),     # one into the next 8 rows (kernel pads with -1)
+    (3000, 16),    # ragged tail
+    (70000, 16),   # two grid steps of BLOCK_ROWS x 128: the histogram accumulates
 ])
 def test_hash_partition_interpret_matches_ref(n, buckets):
     """Satellite: bucket ids match kernels/ref.py and the histogram counts

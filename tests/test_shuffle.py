@@ -337,8 +337,11 @@ def test_token_shuffle_all_to_all(multidevice):
     shards[3][-5:] = -1
     W = np.stack(shards)
 
+    # check_vma=False: the Pallas interpreter runs the kernel body without
+    # varying-axis casts, so its literals fail the check. The compiled TPU
+    # kernel passes it (tests/test_tpu_compile.py).
     @partial(jax.shard_map, mesh=mesh, in_specs=P("all"),
-             out_specs=(P("all"), P("all")), check_rep=False)
+             out_specs=(P("all"), P("all")), check_vma=False)
     def toksh(w):
         recv, hist = spmd.token_shuffle(w[0], "all", capacity=64)
         return recv[None], hist[None]

@@ -1,0 +1,124 @@
+"""Compile the device paths' kernels for a described TPU v5e, with no chip.
+
+The TPU compiler refuses what the Pallas interpreter accepts (tiling,
+shape casts, VMEM limits), so these compiles guard the chip path from a
+CPU host. Nothing here runs: results are checked by the interpret-mode
+tests and on the chip by ``chip_smoke.py``.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+from __future__ import annotations
+
+import os
+from functools import partial
+
+import numpy as np
+import pytest
+
+WIDE = 1 << 22  # tokens per chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip, so keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(topo.devices[:4]), ("all",))
+
+
+@pytest.mark.parametrize("buckets", [4, 256])
+def test_hash_partition_compiles(one_chip, buckets):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.hash_partition import hash_partition
+
+    x = jax.ShapeDtypeStruct((WIDE,), jnp.int32, sharding=one_chip)
+    fn = jax.jit(partial(hash_partition, num_buckets=buckets, interpret=False))
+    assert "tpu_custom_call" in fn.lower(x).compile().as_text()
+
+
+def test_partition_tokens_compiles(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.shuffle import spmd
+
+    x = jax.ShapeDtypeStruct((WIDE,), jnp.int32, sharding=one_chip)
+    fn = jax.jit(partial(spmd.partition_tokens, num_buckets=4, capacity=WIDE // 4,
+                         interpret=False))
+    assert "tpu_custom_call" in fn.lower(x).compile().as_text()
+
+
+def test_token_shuffle_compiles_on_four_chips_with_vma_check(mesh4):
+    """The kernel states its outputs' varying axes, so the shuffle compiles
+    under ``shard_map``'s default ``check_vma=True``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.shuffle import spmd
+
+    n = 1 << 20
+
+    @partial(jax.shard_map, mesh=mesh4, in_specs=P("all"), out_specs=(P("all"), P("all")))
+    def shuffle(w):
+        recv, hist = spmd.token_shuffle(w[0], "all", capacity=n // 2, interpret=False)
+        return recv[None], hist[None]
+
+    x = jax.ShapeDtypeStruct((4, n), jnp.int32, sharding=NamedSharding(mesh4, P("all")))
+    text = jax.jit(shuffle).lower(x).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-to-all" in text
+
+
+def test_wordcount_emit_step_compiles_on_four_switches(mesh4):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro import p4mr
+    from repro.compiler import CostModel
+    from repro.core.topology import TorusTopology
+
+    width = 1 << 20
+    job = p4mr.job("wordcount")
+    keyed = [job.store(f"s{i}", host=f"d{i}", items=width).key_by(4) for i in range(4)]
+    keyed[0].reduce("SUM", *keyed[1:], label="COUNTS").collect("d0", label="OUT")
+    sess = p4mr.Session(TorusTopology(dims=(4,)),
+                        cost_model=CostModel(switch_memory_bytes=1 << 30))
+    step = sess.compile(job).jax_step(axis_name="all")
+    sharded = jax.shard_map(step, mesh=mesh4, in_specs=P("all"), out_specs=P("all"))
+    sds = jax.ShapeDtypeStruct((4, width), jnp.float32,
+                               sharding=NamedSharding(mesh4, P("all")))
+    text = jax.jit(sharded).lower({f"s{i}": sds for i in range(4)}).compile().as_text()
+    assert "collective-permute" in text
