@@ -162,8 +162,8 @@ class PassManager:
     def run(self, ctx: CompileCtx) -> CompileCtx:
         # one ambient-tracer read per compile: when a telemetry Tracer is
         # active (Session / activate()), every pass gets a span carrying
-        # the same wall time the PassRecord records; when none is, the
-        # only cost is this lookup
+        # the same wall time the PassRecord records; when none is, each
+        # pass still gets a (no-op unless profiling) profiler annotation
         from repro.telemetry.trace import current_tracer, maybe_span
 
         tracer = current_tracer()

@@ -122,9 +122,10 @@ def make_train_step(
             (grads, nll, ntok), _ = micro(
                 (gzero, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), batch)
 
-        grads = sync_gradients(grads, pspecs_tree, env)
-        grads, gnorm = clip_by_global_norm(grads, pspecs_tree, env, clip_norm)
-        new_params, new_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            grads = sync_gradients(grads, pspecs_tree, env)
+            grads, gnorm = clip_by_global_norm(grads, pspecs_tree, env, clip_norm)
+            new_params, new_state = opt.update(grads, opt_state, params)
         if opt.eightbit:
             new_state = OptState(new_state.count, _expand(new_state.m, nmesh),
                                  _expand(new_state.v, nmesh))

@@ -90,61 +90,69 @@ def block_apply(kind, p, x, cfg, env, ctx) -> tuple[jax.Array, Any]:
     cache = ctx.get("cache")
     new_cache = {}
     if kind in ("attn_mlp", "attn_local", "enc", "dec", "attn_moe"):
-        h = _ln(p["ln1"], x, cfg, env)
-        window = cfg.window if kind == "attn_local" else None
-        y, c = attn.gqa_apply(
-            p["attn"], h, cfg, env, rope=ctx["rope"],
-            cache=None if cache is None else cache.get("attn"),
-            cache_len=ctx.get("cache_len"), causal=kind != "enc",
-            window=window, impl=ctx["impl"], want_cache=ctx["want_cache"],
-        ) if cfg.mla is None else attn.mla_apply(
-            p["attn"], h, cfg, env, rope=ctx["rope"],
-            cache=None if cache is None else cache.get("attn"),
-            cache_len=ctx.get("cache_len"), impl=ctx["impl"], want_cache=ctx["want_cache"],
-        )
-        if c is not None:
-            new_cache["attn"] = c
-        x = x + y
-        if kind == "dec":
-            h = _ln(p["lnx"], x, cfg, env)
-            cross_cache = None if cache is None else cache.get("cross")
-            y, cx = attn.gqa_apply(
-                p["cross"], h, cfg, env, rope=ctx["rope"], causal=False,
-                impl=ctx["impl"], want_cache=ctx["want_cache"] and cross_cache is None,
-                cross_kv=ctx.get("enc_out"), cross_cache=cross_cache,
+        with jax.named_scope("attention"):
+            h = _ln(p["ln1"], x, cfg, env)
+            window = cfg.window if kind == "attn_local" else None
+            y, c = attn.gqa_apply(
+                p["attn"], h, cfg, env, rope=ctx["rope"],
+                cache=None if cache is None else cache.get("attn"),
+                cache_len=ctx.get("cache_len"), causal=kind != "enc",
+                window=window, impl=ctx["impl"], want_cache=ctx["want_cache"],
+            ) if cfg.mla is None else attn.mla_apply(
+                p["attn"], h, cfg, env, rope=ctx["rope"],
+                cache=None if cache is None else cache.get("attn"),
+                cache_len=ctx.get("cache_len"), impl=ctx["impl"], want_cache=ctx["want_cache"],
             )
-            if ctx["want_cache"]:
-                new_cache["cross"] = cx if cx is not None else cross_cache
+            if c is not None:
+                new_cache["attn"] = c
             x = x + y
-        h = _ln(p["ln2"], x, cfg, env)
+            if kind == "dec":
+                h = _ln(p["lnx"], x, cfg, env)
+                cross_cache = None if cache is None else cache.get("cross")
+                y, cx = attn.gqa_apply(
+                    p["cross"], h, cfg, env, rope=ctx["rope"], causal=False,
+                    impl=ctx["impl"], want_cache=ctx["want_cache"] and cross_cache is None,
+                    cross_kv=ctx.get("enc_out"), cross_cache=cross_cache,
+                )
+                if ctx["want_cache"]:
+                    new_cache["cross"] = cx if cx is not None else cross_cache
+                x = x + y
         if kind == "attn_moe":
-            y, aux = moe_mod.moe_apply(p["moe"], h, cfg, env, decode=ctx.get("decode", False))
-            ctx["aux"] = ctx.get("aux", 0.0) + aux
+            with jax.named_scope("moe"):
+                h = _ln(p["ln2"], x, cfg, env)
+                y, aux = moe_mod.moe_apply(p["moe"], h, cfg, env,
+                                           decode=ctx.get("decode", False))
+                ctx["aux"] = ctx.get("aux", 0.0) + aux
+                x = x + y
         else:
-            y = mlp_apply(p["mlp"], h, cfg, env)
-        x = x + y
+            with jax.named_scope("mlp"):
+                h = _ln(p["ln2"], x, cfg, env)
+                x = x + mlp_apply(p["mlp"], h, cfg, env)
     elif kind == "ssm":
-        h = _ln(p["ln1"], x, cfg, env)
-        y, c = ssm_mod.ssm_apply(
-            p["ssm"], h, cfg, env,
-            state=None if cache is None else cache.get("ssm"),
-            want_state=ctx["want_cache"],
-        )
-        if c is not None:
-            new_cache["ssm"] = c
-        x = x + y
+        with jax.named_scope("ssm"):
+            h = _ln(p["ln1"], x, cfg, env)
+            y, c = ssm_mod.ssm_apply(
+                p["ssm"], h, cfg, env,
+                state=None if cache is None else cache.get("ssm"),
+                want_state=ctx["want_cache"],
+            )
+            if c is not None:
+                new_cache["ssm"] = c
+            x = x + y
     elif kind == "rec":
-        h = _ln(p["ln1"], x, cfg, env)
-        y, c = rglru_mod.rglru_apply(
-            p["rec"], h, cfg, env,
-            state=None if cache is None else cache.get("rec"),
-            want_state=ctx["want_cache"],
-        )
-        if c is not None:
-            new_cache["rec"] = c
-        x = x + y
-        h = _ln(p["ln2"], x, cfg, env)
-        x = x + mlp_apply(p["mlp"], h, cfg, env)
+        with jax.named_scope("rec"):
+            h = _ln(p["ln1"], x, cfg, env)
+            y, c = rglru_mod.rglru_apply(
+                p["rec"], h, cfg, env,
+                state=None if cache is None else cache.get("rec"),
+                want_state=ctx["want_cache"],
+            )
+            if c is not None:
+                new_cache["rec"] = c
+            x = x + y
+        with jax.named_scope("mlp"):
+            h = _ln(p["ln2"], x, cfg, env)
+            x = x + mlp_apply(p["mlp"], h, cfg, env)
     else:
         raise ValueError(kind)
     return x, (new_cache or None)
@@ -306,7 +314,8 @@ def train_loss(params, batch, cfg: ModelConfig, env: ShardEnv, *, impl="masked",
     if cfg.embed_input and not cfg.enc_layers:
         x = batch["embeds"].astype(cfg.compute_dtype)
     else:  # enc-dec: the *decoder* side always consumes tokens
-        x = embed_lookup(batch["tokens"], params["embed"], env, vp)
+        with jax.named_scope("embed"):
+            x = embed_lookup(batch["tokens"], params["embed"], env, vp)
     pos = batch.get("positions")
     if pos is None:
         b, s = x.shape[:2]
@@ -318,10 +327,12 @@ def train_loss(params, batch, cfg: ModelConfig, env: ShardEnv, *, impl="masked",
         memory = encode(params, batch["enc_embeds"].astype(cfg.compute_dtype), cfg, env, enc_pos, impl, unroll=unroll)
         ctx["enc_out"] = memory
         ctx["enc_rope"] = rope_for(cfg, enc_pos, _rope_dim(cfg))
-    x, _, aux = backbone(params, x, cfg, env, ctx)
-    x = _ln(params["final_norm"], x, cfg, env)
-    head = params["embed"] if cfg.tie_embeddings else params["head"]
-    nll = sharded_xent(x, head, batch["labels"], env, cfg.vocab, vp)
+    with jax.named_scope("layers"):
+        x, _, aux = backbone(params, x, cfg, env, ctx)
+    with jax.named_scope("head"):
+        x = _ln(params["final_norm"], x, cfg, env)
+        head = params["embed"] if cfg.tie_embeddings else params["head"]
+        nll = sharded_xent(x, head, batch["labels"], env, cfg.vocab, vp)
     ntok = jnp.sum(batch["labels"] >= 0)
     return jnp.sum(nll) + aux, {"nll_sum": jnp.sum(nll), "ntok": ntok}
 

@@ -65,15 +65,18 @@ def partition_tokens(
     from repro.kernels import ops
 
     kw = {} if interpret is None else {"interpret": interpret}
-    ids, hist = ops.hash_partition(tokens, num_buckets, **kw)
-    onehot = ids[:, None] == jnp.arange(num_buckets)[None, :]  # (n, B), False for -1
-    slot = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1  # rank within bucket
-    slot = jnp.where(onehot, slot, 0).sum(axis=1)
-    ok = (ids >= 0) & (slot < capacity)
-    buf = jnp.full((num_buckets, capacity), -1, tokens.dtype)
-    # invalid/overflow tokens scatter to an out-of-bounds row and are dropped
-    row = jnp.where(ok, ids, num_buckets)
-    buf = buf.at[row, jnp.clip(slot, 0, capacity - 1)].set(tokens, mode="drop")
+    with jax.named_scope("hash"):
+        ids, hist = ops.hash_partition(tokens, num_buckets, **kw)
+    with jax.named_scope("rank"):
+        onehot = ids[:, None] == jnp.arange(num_buckets)[None, :]  # (n, B), False for -1
+        slot = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1  # rank within bucket
+        slot = jnp.where(onehot, slot, 0).sum(axis=1)
+    with jax.named_scope("pack"):
+        ok = (ids >= 0) & (slot < capacity)
+        buf = jnp.full((num_buckets, capacity), -1, tokens.dtype)
+        # invalid/overflow tokens scatter to an out-of-bounds row and are dropped
+        row = jnp.where(ok, ids, num_buckets)
+        buf = buf.at[row, jnp.clip(slot, 0, capacity - 1)].set(tokens, mode="drop")
     return buf, hist
 
 
@@ -89,5 +92,6 @@ def token_shuffle(
     with -1 padding, this mapper's per-bucket histogram)."""
     p = lax.axis_size(axis_name)
     buf, hist = partition_tokens(tokens, p, capacity=capacity, interpret=interpret)
-    recv = lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0, tiled=False)
+    with jax.named_scope("exchange"):
+        recv = lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=0, tiled=False)
     return recv.reshape(-1), hist

@@ -16,9 +16,15 @@ search / plan signature, the active tracer is ambient state in a
         compiler.compile(...)        # pass spans land on ``tracer``
     tracer.write("trace.json")
 
-and instrumented call sites do ``maybe_span(current_tracer(), ...)`` —
-a ``nullcontext`` when no tracer is active, so the un-traced fast path
-pays one contextvar read per instrumented call and nothing else.
+and instrumented call sites do ``maybe_span(current_tracer(), ...)``.
+
+Every span, with a tracer or without one, is also a JAX profiler
+``TraceAnnotation`` of the same name, so ``plan.run``, ``pass:<name>``,
+``tune:round-<n>`` and ``verify.plan`` show on the profiler's host plane
+on one clock with the device ops. When no profile is being recorded the
+annotation does nothing, so the un-traced fast path pays one contextvar
+read and one no-op annotation per instrumented call. A process that has
+not imported JAX cannot be profiled, and gets no annotation at all.
 
 ``validate_chrome_trace`` is the schema check CI's trace-smoke step (and
 ``tests/test_telemetry.py``) runs over the artifact: valid structure,
@@ -31,6 +37,7 @@ import contextlib
 import contextvars
 import dataclasses
 import json
+import sys
 import time
 from typing import Any, Iterator, Mapping
 
@@ -73,7 +80,8 @@ class Tracer:
         start = self._now_us()
         frame = dict(attrs)
         try:
-            yield frame
+            with _annotation(name):
+                yield frame
         finally:
             end = self._now_us()
             self.spans.append(
@@ -192,10 +200,26 @@ def activate(tracer: Tracer) -> Iterator[Tracer]:
 
 
 def maybe_span(tracer: Tracer | None, name: str, **attrs: Any):
-    """``tracer.span(...)`` or a no-op context yielding a throwaway dict."""
+    """``tracer.span(...)``, or with no tracer only the profiler
+    annotation, yielding a throwaway dict."""
     if tracer is None:
-        return contextlib.nullcontext({})
+        return _annotated(name)
     return tracer.span(name, **attrs)
+
+
+def _annotation(name: str):
+    """A JAX profiler ``TraceAnnotation`` named ``name``; a no-op where
+    JAX is not imported, since then no profile can be recording."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
+@contextlib.contextmanager
+def _annotated(name: str) -> Iterator[dict[str, Any]]:
+    with _annotation(name):
+        yield {}
 
 
 # ----------------------------------------------------------- validation --

@@ -471,3 +471,42 @@ def test_timeline_present_for_both_engines_without_session():
         assert tl is not None and tl.engine == engine
         assert tl.to_dict()["interval_ticks"] == 4.0
         assert tl.depth_integral() >= 0.0
+
+
+def test_spans_are_profiler_annotations(multidevice):
+    """Spans land on the JAX profiler's host plane (with no ``Tracer``
+    active too), on one clock with the device ops; the ``Tracer``'s own
+    export still holds them."""
+    out = multidevice("""
+    import glob, tempfile
+    import jax, numpy as np
+    from repro import p4mr
+    from repro.core import topology, wordcount
+    from repro.telemetry.trace import Tracer, activate
+
+    n, vocab = 4, 16
+    job = p4mr.job("wc")
+    keyed = [job.store(f"s{i}", host=f"d{i}", items=vocab).key_by(n) for i in range(n)]
+    keyed[0].reduce("SUM", *keyed[1:], label="COUNTS").collect("d0", label="OUT")
+    rs = np.random.RandomState(5)
+    shards = [rs.randint(0, vocab, (40,)).astype(np.int32) for _ in range(n)]
+    hists = {f"s{i}": wordcount.wordcount_reference([w], vocab).astype(np.float32)
+             for i, w in enumerate(shards)}
+    tracer = Tracer()
+    log_dir = tempfile.mkdtemp()
+    with jax.profiler.trace(log_dir):
+        plan = p4mr.Session(topology.TorusTopology(dims=(n,))).compile(job)
+        with activate(tracer):
+            got = plan.run(hists, backend="jax")
+    np.testing.assert_array_equal(
+        got["OUT"].astype(np.int64), wordcount.wordcount_reference(shards, vocab))
+    path, = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    host = {ev.name for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events}
+    print("HOST", sorted(n for n in host if n == "plan.run" or n.startswith("pass:")))
+    print("TRACER", sorted({s.name for s in tracer.spans}))
+    """, n_devices=4)
+    host = out.split("HOST ", 1)[1].splitlines()[0]
+    assert "'plan.run'" in host and "'pass:" in host
+    assert "'plan.run'" in out.split("TRACER ", 1)[1]
