@@ -11,8 +11,11 @@ own ``all_to_all`` anymore:
   while shuffling" step (KEYBY + per-bucket SUM in one collective).
 * ``partition_tokens``  — the switch MAPPER: the Pallas ``hash_partition``
   kernel computes each token's routing id and the per-bucket histogram
-  (the capacity signal), then tokens are packed into a capacity-sized
-  send buffer.
+  (the capacity signal), then one sort by bucket id (and position, so
+  the order within a bucket is kept) lays each bucket's tokens out as one
+  contiguous run in arrival order, and the capacity-sized send buffer is
+  B slices of it, one per bucket, starting where the histogram's
+  exclusive cumsum says.
 * ``token_shuffle``     — ``partition_tokens`` + one capacity-sized
   ``all_to_all``: raw tokens land on the reducer that owns their hash
   bucket, padding slots carry -1.
@@ -29,6 +32,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+INT32_KEYS = 2**31  # sort keys below this fit in int32
 
 
 def shuffle_reduce(values: jax.Array, axis_name: str = "all") -> jax.Array:
@@ -58,25 +63,37 @@ def partition_tokens(
     buffer by hash bucket (padding -1), plus the per-bucket histogram.
 
     The bucket ids and histogram come from the Pallas ``hash_partition``
-    kernel (the p4mr mapper); ``capacity`` is static (SPMD shapes), sized
-    from the histogram's max — tokens beyond a bucket's capacity are
-    dropped, so size it to ``hist.max()`` upstream.
+    kernel (the p4mr mapper). One sort keyed by bucket id (padding keyed
+    past the last bucket) puts each bucket's tokens in one run, in
+    arrival order: the key is ``bucket · n + position``, unique, where
+    that fits in int32, else the bucket id alone under a stable sort.
+    Bucket b's run starts at the exclusive cumsum of the histogram, and
+    its row of the buffer is the ``capacity`` tokens from there, slots
+    past ``hist[b]`` set to -1. ``capacity`` is static (SPMD shapes),
+    sized from the histogram's max — tokens beyond a bucket's capacity
+    are dropped, so size it to ``hist.max()`` upstream.
     """
     from repro.kernels import ops
 
     kw = {} if interpret is None else {"interpret": interpret}
     with jax.named_scope("hash"):
         ids, hist = ops.hash_partition(tokens, num_buckets, **kw)
+    n = tokens.shape[0]
+    unique = (num_buckets + 1) * n <= INT32_KEYS
     with jax.named_scope("rank"):
-        onehot = ids[:, None] == jnp.arange(num_buckets)[None, :]  # (n, B), False for -1
-        slot = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1  # rank within bucket
-        slot = jnp.where(onehot, slot, 0).sum(axis=1)
+        key = jnp.where(ids >= 0, ids, num_buckets)  # padding sorts last
+        if unique:  # ties broken by position, so an unstable sort keeps arrival order
+            key = key * n + lax.iota(jnp.int32, n)
+        start = jnp.cumsum(hist) - hist  # where each bucket's run begins
     with jax.named_scope("pack"):
-        ok = (ids >= 0) & (slot < capacity)
-        buf = jnp.full((num_buckets, capacity), -1, tokens.dtype)
-        # invalid/overflow tokens scatter to an out-of-bounds row and are dropped
-        row = jnp.where(ok, ids, num_buckets)
-        buf = buf.at[row, jnp.clip(slot, 0, capacity - 1)].set(tokens, mode="drop")
+        # the TPU's stable sort carries the positions as a third operand,
+        # so the unique key sorts faster
+        _, runs = lax.sort((key, tokens), num_keys=1, is_stable=not unique)
+        # capacity slots of padding past the end, so no slice start is clamped
+        runs = jnp.pad(runs, (0, capacity), constant_values=-1)
+        buf = jnp.stack([lax.dynamic_slice(runs, (start[b],), (capacity,))
+                         for b in range(num_buckets)])
+        buf = jnp.where(jnp.arange(capacity)[None, :] < hist[:, None], buf, -1)
     return buf, hist
 
 

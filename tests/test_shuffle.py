@@ -361,6 +361,56 @@ def test_token_shuffle_all_to_all(multidevice):
     assert "OK" in out
 
 
+def _partition_oracle(words: np.ndarray, buckets: int, capacity: int):
+    """numpy mapper: bucket b's row holds its words in arrival order, cut
+    at ``capacity``, padding -1; the histogram counts words, not padding."""
+    h = (words.astype(np.uint32) * np.uint32(0x9E3779B1)) >> np.uint32(16)
+    ids = np.where(words >= 0, (h % np.uint32(buckets)).astype(np.int64), -1)
+    hist = np.bincount(ids[ids >= 0], minlength=buckets).astype(np.int32)
+    buf = np.full((buckets, capacity), -1, np.int32)
+    for b in range(buckets):
+        mine = words[ids == b][:capacity]
+        buf[b, : mine.size] = mine
+    return buf, hist
+
+
+@pytest.mark.parametrize("buckets,n,cap", [
+    (1, 1000, "largest"),
+    (3, 999, "largest"),
+    (4, 1000, "overflow"),
+    (8, 1531, "largest"),
+    (4, 777, "above_n"),
+    (8, 2049, "overflow"),
+    (4, 3 * 65536 + 77, "largest"),  # several kernel tiles
+])
+@pytest.mark.parametrize("key", ["unique", "stable"])
+def test_partition_tokens_matches_numpy_oracle(monkeypatch, buckets, n, cap, key):
+    """The mapper's send buffer, slot for slot, and its histogram: words
+    not a multiple of 128, -1 padding scattered through them, a bucket
+    past ``capacity`` cut there, and ``capacity`` above n; under the
+    unique ``bucket · n + position`` key, and under the stable sort by
+    bucket that inputs too long for that key in int32 take."""
+    import jax
+    from functools import partial
+
+    from repro.shuffle import spmd
+
+    if key == "stable":
+        monkeypatch.setattr(spmd, "INT32_KEYS", 0)
+    rs = np.random.RandomState(buckets * 7919 + n)
+    words = rs.randint(0, 1 << 20, size=n).astype(np.int32)
+    words[rs.rand(n) < 0.05] = -1
+    largest = int(_partition_oracle(words, buckets, 1)[1].max())
+    capacity = {"largest": largest, "overflow": largest // 2, "above_n": n + 37}[cap]
+    want_buf, want_hist = _partition_oracle(words, buckets, capacity)
+    fn = jax.jit(partial(spmd.partition_tokens, num_buckets=buckets, capacity=capacity,
+                         interpret=True))
+    buf, hist = jax.device_get(fn(words))
+    assert buf.shape == (buckets, capacity) and buf.dtype == np.int32
+    np.testing.assert_array_equal(hist, want_hist)
+    np.testing.assert_array_equal(buf, want_buf)
+
+
 # ---------------------------------------------------------- scenarios --
 def test_scenarios_use_compiled_shuffle():
     topo = topology.TorusTopology(dims=(8,))

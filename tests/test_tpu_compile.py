@@ -76,7 +76,11 @@ def test_partition_tokens_compiles(one_chip):
     x = jax.ShapeDtypeStruct((WIDE,), jnp.int32, sharding=one_chip)
     fn = jax.jit(partial(spmd.partition_tokens, num_buckets=4, capacity=WIDE // 4,
                          interpret=False))
-    assert "tpu_custom_call" in fn.lower(x).compile().as_text()
+    text = fn.lower(x).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the buffer is packed by one sort by bucket, not by a scatter
+    assert "sort(" in text
+    assert "scatter(" not in text
 
 
 def test_token_shuffle_compiles_on_four_chips_with_vma_check(mesh4):
