@@ -43,7 +43,7 @@ def main():
                  check_vma=False)
         def f(p, xx):
             fn = moe_mod.moe_apply_a2a if mode == "a2a" else moe_mod.moe_apply_replicated
-            y, _ = fn(p["moe"], xx, cfg, env)
+            y, _, _ = fn(p["moe"], xx, cfg, env)
             return y
         return np.asarray(f(params, jnp.asarray(x)))
 

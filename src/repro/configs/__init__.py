@@ -19,6 +19,7 @@ ARCHS = [
     "qwen2_vl_7b",
     "seamless_m4t_large_v2",
     "recurrentgemma_2b",
+    "moonlight_16b_a3b",
 ]
 
 _ALIAS = {a.replace("_", "-"): a for a in ARCHS}
@@ -34,6 +35,7 @@ _ALIAS.update({
     "qwen2-vl-7b": "qwen2_vl_7b",
     "seamless-m4t-large-v2": "seamless_m4t_large_v2",
     "recurrentgemma-2b": "recurrentgemma_2b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 })
 
 

@@ -59,6 +59,22 @@ def _prepend_spec(tree, env: ShardEnv):
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
+ROUTING_METRICS = ("moe_load_imbalance", "moe_dropped", "moe_held_rows")
+
+
+def routing_metrics(routed: dict, axes) -> dict:
+    """A train step's routing counters, from each MoE block's stats (stacked
+    over the scanned blocks): the worst block's largest held-expert load over
+    its mean held load, assignments dropped, and the rows the held experts
+    computed (a chip's, the most over the chips)."""
+    held = jnp.stack([v["held"] for v in routed.values()])  # (blocks, n_sb, held)
+    imbalance = jnp.max(jnp.max(held, -1) / jnp.maximum(jnp.mean(held, -1), 1e-9))
+    dropped = sum(jnp.sum(v["dropped"]) for v in routed.values())
+    return {"moe_load_imbalance": lax.pmax(imbalance, axes),
+            "moe_dropped": lax.psum(dropped, axes),
+            "moe_held_rows": lax.pmax(jnp.sum(held), axes)}
+
+
 def make_train_step(
     cfg: ModelConfig,
     mesh,
@@ -102,7 +118,7 @@ def make_train_step(
             (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
             gacc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(jnp.float32) / microbatches, gacc, grads)
-            return (gacc, nll + aux["nll_sum"], ntok + aux["ntok"]), None
+            return (gacc, nll + aux["nll_sum"], ntok + aux["ntok"]), aux.get("moe", {})
 
         gzero = jax.tree_util.tree_map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -113,13 +129,17 @@ def make_train_step(
             init = (gzero, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
             if unroll:  # cost probes: loop bodies must be HLO-visible
                 carry = init
+                routed = []
                 for i in range(microbatches):
-                    carry, _ = micro(carry, jax.tree_util.tree_map(lambda a: a[i], mbs))
+                    carry, r = micro(carry, jax.tree_util.tree_map(lambda a: a[i], mbs))
+                    routed.append(r)
                 (grads, nll, ntok) = carry
+                routed = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *routed)
             else:
-                (grads, nll, ntok), _ = lax.scan(micro, init, mbs)
+                (grads, nll, ntok), routed = lax.scan(micro, init, mbs)
+            routed = jax.tree_util.tree_map(lambda a: jnp.sum(a, 0), routed)
         else:
-            (grads, nll, ntok), _ = micro(
+            (grads, nll, ntok), routed = micro(
                 (gzero, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), batch)
 
         with jax.named_scope("optimizer"):
@@ -136,6 +156,11 @@ def make_train_step(
             "grad_norm": gnorm,
             "lr": opt.schedule(new_state.count),
         }
+        if routed:
+            new_params = M.update_router_bias(
+                params, new_params, {k: lax.psum(v["load"], axes) for k, v in routed.items()},
+                cfg)
+            metrics.update(routing_metrics(routed, axes))
         return new_params, new_state, metrics
 
     # optimizer moments inherit the param layout (storage-sharded); 8-bit
@@ -150,6 +175,8 @@ def make_train_step(
     else:
         state_part = OptState(count=P(), m=p_part, v=p_part)
     metrics_part = {"loss": P(), "ntok": P(), "grad_norm": P(), "lr": P()}
+    if cfg.moe is not None:
+        metrics_part.update({k: P() for k in ROUTING_METRICS})
 
     def init_state_fn(params):
         st = opt.init(params)

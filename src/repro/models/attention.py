@@ -47,10 +47,14 @@ def attention_specs(cfg: ModelConfig, model_size: int) -> dict:
     if cfg.mla is not None:
         m = cfg.mla
         qk_hd = m.qk_nope_head_dim + m.qk_rope_head_dim
+        if m.q_lora_rank is None:
+            q = {"wq": LeafSpec((d, cfg.n_heads * qk_hd), tp_dim=1, fsdp_dim=0)}
+        else:
+            q = {"wq_a": LeafSpec((d, m.q_lora_rank), tp_dim=None, fsdp_dim=0),
+                 "q_norm": LeafSpec((m.q_lora_rank,), init="ones"),
+                 "wq_b": LeafSpec((m.q_lora_rank, cfg.n_heads * qk_hd), tp_dim=1, fsdp_dim=0)}
         return {
-            "wq_a": LeafSpec((d, m.q_lora_rank), tp_dim=None, fsdp_dim=0),
-            "q_norm": LeafSpec((m.q_lora_rank,), init="ones"),
-            "wq_b": LeafSpec((m.q_lora_rank, cfg.n_heads * qk_hd), tp_dim=1, fsdp_dim=0),
+            **q,
             "wkv_a": LeafSpec((d, m.kv_lora_rank + m.qk_rope_head_dim), tp_dim=None, fsdp_dim=0),
             "kv_norm": LeafSpec((m.kv_lora_rank,), init="ones"),
             "wkv_b": LeafSpec(
@@ -356,10 +360,14 @@ def mla_apply(
     dn, dr, dv, dc = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim, m.kv_lora_rank
     cos, sin = rope
 
-    wq_a = fetch_weight(p["wq_a"], env, tp_dim=None, fsdp_dim=0)
-    cq = rms_norm(x @ wq_a.astype(x.dtype), fetch_weight(p["q_norm"], env, tp_dim=None, fsdp_dim=None), cfg.norm_eps)
-    wq_b = fetch_weight(p["wq_b"], env, tp_dim=1, fsdp_dim=0)
-    q = (cq @ wq_b.astype(x.dtype)).reshape(b, s, h_loc, dn + dr)
+    if "wq" in p:  # no query low-rank
+        q = x @ fetch_weight(p["wq"], env, tp_dim=1, fsdp_dim=0).astype(x.dtype)
+    else:
+        wq_a = fetch_weight(p["wq_a"], env, tp_dim=None, fsdp_dim=0)
+        q_norm = fetch_weight(p["q_norm"], env, tp_dim=None, fsdp_dim=None)
+        cq = rms_norm(x @ wq_a.astype(x.dtype), q_norm, cfg.norm_eps)
+        q = cq @ fetch_weight(p["wq_b"], env, tp_dim=1, fsdp_dim=0).astype(x.dtype)
+    q = q.reshape(b, s, h_loc, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, cos, sin)
 

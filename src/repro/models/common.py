@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 768
+    q_lora_rank: int | None = 768  # None: queries straight from x (one ``wq``)
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
@@ -37,7 +37,25 @@ class MoEConfig:
     # 'a2a'  — sequence-sharded dispatch via all_to_all (the word-count
     #          shuffle, paper-faithful);
     # 'replicated' — TP-replicated tokens, expert masking + psum combine.
+    # Either way a layer with no exchange (tp = 1) computes its held
+    # experts over the rows routed to them (moe.moe_apply_held).
     dispatch: str = "a2a"
+    n_shared: int = 0  # shared experts: one gated MLP of n_shared * d_expert, every token
+    # 'softmax': gates are the top-k probabilities, switch-style aux loss;
+    # 'sigmoid': per-expert sigmoid scores (DeepSeek-V3), sequence-wise aux loss
+    scoring: str = "softmax"
+    routed_scale: float = 1.0  # routed experts' gates times this
+    # > 0: top-k on score + a correction bias, moved by this much per step
+    # towards balance (sign rule), outside the optimizer; gates stay unbiased
+    bias_rate: float = 0.0
+    held: int | None = None  # experts held here (0 .. held-1 of n_experts); None: all
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
+
+    def expert_params(self, d_model: int) -> int:
+        return 3 * d_model * self.d_expert
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,6 +90,7 @@ class ModelConfig:
     ssm: SSMConfig | None = None
     pattern: tuple[str, ...] | None = None  # hybrid superblock, e.g. ("rec","rec","attn")
     pattern_tail: tuple[str, ...] = ()  # layers after the scanned superblocks
+    first_dense: int = 0  # leading dense (attn_mlp) layers, run once before the scan
     enc_layers: int = 0  # >0 → encoder-decoder
     embed_input: bool = False  # modality frontend stub feeds embeddings
     tie_embeddings: bool = True
@@ -108,24 +127,27 @@ class ModelConfig:
                 return tp
         return 1
 
+    def _attention_params(self) -> int:
+        d, hd, H, KV = self.d_model, self.hd, self.n_heads, self.n_kv_heads
+        if self.mla is None:
+            return d * hd * (H + 2 * KV) + H * hd * d
+        m = self.mla
+        qk = H * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+        n = d * qk if m.q_lora_rank is None else d * m.q_lora_rank + m.q_lora_rank * qk
+        n += d * (m.kv_lora_rank + m.qk_rope_head_dim)
+        n += m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
+        return n + H * m.v_head_dim * d
+
     def param_count(self) -> int:
         """Total logical parameters (approx; excludes dup copies)."""
         d, ff, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab
-        hd, H, KV = self.hd, self.n_heads, self.n_kv_heads
         emb = V * d * (1 if self.tie_embeddings else 2)
         per_layer = 0
         if self.family != "ssm":
-            if self.mla is not None:
-                m = self.mla
-                per_layer += d * m.q_lora_rank + m.q_lora_rank * H * (m.qk_nope_head_dim + m.qk_rope_head_dim)
-                per_layer += d * (m.kv_lora_rank + m.qk_rope_head_dim)
-                per_layer += m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
-                per_layer += H * m.v_head_dim * d
-            else:
-                per_layer += d * hd * (H + 2 * KV) + H * hd * d
+            per_layer += self._attention_params()
         if self.moe is not None:
             per_layer += d * self.moe.n_experts  # router
-            per_layer += self.moe.n_experts * 3 * d * self.moe.d_expert
+            per_layer += (self.moe.n_experts + self.moe.n_shared) * self.moe.expert_params(d)
         elif ff:
             per_layer += 3 * d * ff  # gated mlp
         if self.family == "ssm":
@@ -137,15 +159,17 @@ class ModelConfig:
         if self.family == "hybrid":
             # mix of recurrent + attn layers; approximate via pattern ratio
             pass
-        layers = L + self.enc_layers
-        return emb + layers * per_layer
+        layers = L - self.first_dense + self.enc_layers
+        lead = self.first_dense * (self._attention_params() + 3 * d * ff)
+        return emb + lead + layers * per_layer
 
     def active_param_count(self) -> int:
-        """Active per-token params (MoE: top_k of n_experts)."""
+        """Active per-token params (MoE: top_k of n_experts, shared experts whole)."""
         if self.moe is None:
             return self.param_count()
         total = self.param_count()
-        expert = self.n_layers * self.moe.n_experts * 3 * self.d_model * self.moe.d_expert
+        expert = ((self.n_layers - self.first_dense) * self.moe.n_experts
+                  * self.moe.expert_params(self.d_model))
         active = expert * self.moe.top_k // self.moe.n_experts
         return total - expert + active
 

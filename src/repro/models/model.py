@@ -49,10 +49,15 @@ def block_pattern(cfg: ModelConfig) -> tuple[tuple[str, ...], tuple[str, ...], i
     if cfg.family == "ssm":
         return ("ssm",), (), cfg.n_layers
     if cfg.family == "moe":
-        return ("attn_moe",), (), cfg.n_layers
+        return ("attn_moe",), (), cfg.n_layers - cfg.first_dense
     if cfg.family == "encdec":
         return ("dec",), (), cfg.n_layers
     return ("attn_mlp",), (), cfg.n_layers
+
+
+def lead_pattern(cfg: ModelConfig) -> tuple[str, ...]:
+    """The leading dense layers, run once each before the scanned blocks."""
+    return ("attn_mlp",) * cfg.first_dense
 
 
 def _norm_spec(cfg: ModelConfig) -> LeafSpec:
@@ -120,9 +125,10 @@ def block_apply(kind, p, x, cfg, env, ctx) -> tuple[jax.Array, Any]:
         if kind == "attn_moe":
             with jax.named_scope("moe"):
                 h = _ln(p["ln2"], x, cfg, env)
-                y, aux = moe_mod.moe_apply(p["moe"], h, cfg, env,
-                                           decode=ctx.get("decode", False))
+                y, aux, stats = moe_mod.moe_apply(p["moe"], h, cfg, env,
+                                                  decode=ctx.get("decode", False))
                 ctx["aux"] = ctx.get("aux", 0.0) + aux
+                ctx["stats"] = stats
                 x = x + y
         else:
             with jax.named_scope("mlp"):
@@ -178,6 +184,9 @@ def param_specs(cfg: ModelConfig, env: ShardEnv) -> dict:
             is_leaf=lambda v: isinstance(v, LeafSpec),
         )
     specs["blocks"] = blocks
+    lead = lead_pattern(cfg)
+    if lead:
+        specs["lead"] = {f"{i}_{kind}": block_specs(kind, cfg, env) for i, kind in enumerate(lead)}
     if tail:
         specs["tail"] = {f"{i}_{kind}": block_specs(kind, cfg, env) for i, kind in enumerate(tail)}
     if cfg.enc_layers:
@@ -210,14 +219,18 @@ def _rope_dim(cfg: ModelConfig) -> int:
 # backbone forward (inside shard_map; x already embedded)
 # ---------------------------------------------------------------------------
 def backbone(params, x, cfg: ModelConfig, env: ShardEnv, ctx, caches=None):
-    """Run all blocks. caches: {"blocks": stacked pytree, "tail": ...} or
-    None. Returns (x, new_caches, aux).
+    """Run all blocks: the leading dense layers, the scanned superblocks,
+    the tail. caches: {"blocks": stacked pytree, "lead": ..., "tail": ...}
+    or None. Returns (x, new_caches, aux, stats): ``stats`` holds each
+    scanned MoE block's routing counts stacked over the superblocks
+    ({} for a model without experts).
 
     ctx["unroll"]: python-unroll the superblock loop instead of lax.scan —
     used by the roofline cost probes (XLA cost_analysis counts loop bodies
     once, so probes must be loop-free; see analysis/roofline.py).
     """
     unit, tail, n_sb = block_pattern(cfg)
+    lead = lead_pattern(cfg)
     ctx = dict(ctx)
     ctx["aux"] = 0.0
     want_cache = ctx["want_cache"]
@@ -227,18 +240,47 @@ def backbone(params, x, cfg: ModelConfig, env: ShardEnv, ctx, caches=None):
         p_sb, cache_sb = xs
         c = dict(ctx)
         c["aux"] = 0.0
-        new_cs = {}
+        new_cs, stats = {}, {}
         for pos, kind in enumerate(unit):
             key = f"{pos}_{kind}"
             c["cache"] = None if cache_sb is None else cache_sb[key]
             x, nc = block_apply(kind, p_sb[key], x, cfg, env, c)
             if nc is not None:
                 new_cs[key] = nc
-        return (x, aux_in + c["aux"]), (new_cs or None)
+            if "stats" in c:
+                stats[key] = c.pop("stats")
+        return (x, aux_in + c["aux"]), (new_cs or None, stats)
 
     body = sb_body
     if cfg.remat and not ctx.get("decode"):
         body = jax.checkpoint(sb_body)
+
+    def run_listed(x, kinds, group, remat):
+        """Blocks run once each, in order (the lead and the tail)."""
+        aux, new = 0.0, {}
+        for i, kind in enumerate(kinds):
+            key = f"{i}_{kind}"
+            cache = None if caches is None else caches[group][key]
+
+            def one(x, p, cache, kind=kind):
+                c = dict(ctx)
+                c["aux"] = 0.0
+                c["cache"] = cache
+                x, nc = block_apply(kind, p, x, cfg, env, c)
+                return x, nc, c["aux"]
+
+            if remat:
+                one = jax.checkpoint(one)
+            x, nc, a = one(x, params[group][key], cache)
+            aux = aux + a
+            if nc is not None:
+                new[key] = nc
+        return x, aux, new
+
+    new_lead = {}
+    lead_aux = 0.0
+    if lead:
+        x, lead_aux, new_lead = run_listed(x, lead, "lead", cfg.remat and not ctx.get("decode"))
 
     cache_blocks = None if caches is None else caches["blocks"]
     if ctx.get("unroll"):
@@ -251,31 +293,29 @@ def backbone(params, x, cfg: ModelConfig, env: ShardEnv, ctx, caches=None):
             carry, y = body(carry, (p_i, c_i))
             ys.append(y)
         (x, aux) = carry
-        new_blocks = (None if ys[0] is None
-                      else jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys))
+        new_blocks = (None if ys[0][0] is None
+                      else jax.tree_util.tree_map(lambda *a: jnp.stack(a), *[y[0] for y in ys]))
+        stats = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *[y[1] for y in ys])
     elif cache_blocks is None:
-        (x, aux), new_blocks = lax.scan(
+        (x, aux), (new_blocks, stats) = lax.scan(
             lambda c, p: body(c, (p, None)), (x, 0.0), params["blocks"]
         )
     else:
-        (x, aux), new_blocks = lax.scan(body, (x, 0.0), (params["blocks"], cache_blocks))
+        (x, aux), (new_blocks, stats) = lax.scan(
+            body, (x, 0.0), (params["blocks"], cache_blocks))
+    if lead:
+        aux = aux + lead_aux
 
     new_caches = {"blocks": new_blocks} if (want_cache or caches is not None) else None
+    if new_caches is not None and lead:
+        new_caches["lead"] = new_lead
 
     if tail:
-        new_tail = {}
-        for i, kind in enumerate(tail):
-            key = f"{i}_{kind}"
-            c = dict(ctx)
-            c["aux"] = 0.0
-            c["cache"] = None if caches is None else caches["tail"][key]
-            x, nc = block_apply(kind, params["tail"][key], x, cfg, env, c)
-            aux = aux + c["aux"]
-            if nc is not None:
-                new_tail[key] = nc
+        x, tail_aux, new_tail = run_listed(x, tail, "tail", False)
+        aux = aux + tail_aux
         if new_caches is not None:
             new_caches["tail"] = new_tail
-    return x, new_caches, aux
+    return x, new_caches, aux, stats
 
 
 def encode(params, embeds, cfg: ModelConfig, env: ShardEnv, enc_positions, impl,
@@ -309,7 +349,8 @@ def _squeeze_mesh_dims(tree, n: int):
 def train_loss(params, batch, cfg: ModelConfig, env: ShardEnv, *, impl="masked", unroll=False):
     """batch: dict with device-major leading dims already squeezed:
     tokens/labels (b_loc, s) int32; embeds (b_loc, s, d) when embed_input;
-    positions (b_loc, s[, 3]). Returns (loss_local, aux_metrics)."""
+    positions (b_loc, s[, 3]). Returns (loss_local, aux_metrics); with MoE
+    blocks aux_metrics["moe"] holds their routing stats."""
     vp = pad_vocab(cfg.vocab, env.model_size)
     if cfg.embed_input and not cfg.enc_layers:
         x = batch["embeds"].astype(cfg.compute_dtype)
@@ -328,13 +369,16 @@ def train_loss(params, batch, cfg: ModelConfig, env: ShardEnv, *, impl="masked",
         ctx["enc_out"] = memory
         ctx["enc_rope"] = rope_for(cfg, enc_pos, _rope_dim(cfg))
     with jax.named_scope("layers"):
-        x, _, aux = backbone(params, x, cfg, env, ctx)
+        x, _, aux, stats = backbone(params, x, cfg, env, ctx)
     with jax.named_scope("head"):
         x = _ln(params["final_norm"], x, cfg, env)
         head = params["embed"] if cfg.tie_embeddings else params["head"]
         nll = sharded_xent(x, head, batch["labels"], env, cfg.vocab, vp)
     ntok = jnp.sum(batch["labels"] >= 0)
-    return jnp.sum(nll) + aux, {"nll_sum": jnp.sum(nll), "ntok": ntok}
+    metrics = {"nll_sum": jnp.sum(nll), "ntok": ntok}
+    if stats:
+        metrics["moe"] = stats
+    return jnp.sum(nll) + aux, metrics
 
 
 def prefill(params, batch, cfg: ModelConfig, env: ShardEnv, *, impl="masked", unroll=False):
@@ -355,7 +399,7 @@ def prefill(params, batch, cfg: ModelConfig, env: ShardEnv, *, impl="masked", un
         memory = encode(params, batch["enc_embeds"].astype(cfg.compute_dtype), cfg, env, enc_pos, impl, unroll=unroll)
         ctx["enc_out"] = memory
         ctx["enc_rope"] = rope_for(cfg, enc_pos, _rope_dim(cfg))
-    x, caches, _ = backbone(params, x, cfg, env, ctx)
+    x, caches, _, _ = backbone(params, x, cfg, env, ctx)
     x = _ln(params["final_norm"], x, cfg, env)
     head = params["embed"] if cfg.tie_embeddings else params["head"]
     nxt = argmax_logits(x[:, -1:], head, env, cfg.vocab)
@@ -379,8 +423,25 @@ def decode_step(params, cache, tokens, cache_len, cfg: ModelConfig, env: ShardEn
     if cfg.enc_layers:
         ctx["enc_out"] = None  # cross kv lives in the cache
         ctx["enc_rope"] = None
-    x, new_cache, _ = backbone(params, x, cfg, env, ctx, caches=cache)
+    x, new_cache, _, _ = backbone(params, x, cfg, env, ctx, caches=cache)
     x = _ln(params["final_norm"], x, cfg, env)
     head = params["embed"] if cfg.tie_embeddings else params["head"]
     nxt = argmax_logits(x, head, env, cfg.vocab)
     return nxt[:, 0], new_cache
+
+
+def update_router_bias(params, new_params, load, cfg: ModelConfig):
+    """``new_params`` with each MoE block's correction bias stepped from its
+    value in ``params``, outside the optimizer: ``b_e += bias_rate ·
+    sign(mean load − load_e)``. ``load``: per block, the step's assignments
+    per expert, (n_sb, E)."""
+    if cfg.moe is None or not cfg.moe.bias_rate:
+        return new_params
+    blocks = dict(new_params["blocks"])
+    for key, lo in load.items():
+        bias = params["blocks"][key]["moe"]["bias"]
+        lo = lo.astype(jnp.float32)
+        step = jnp.sign(jnp.mean(lo, axis=-1, keepdims=True) - lo) * cfg.moe.bias_rate
+        moe = dict(blocks[key]["moe"], bias=bias + step.astype(bias.dtype))
+        blocks[key] = dict(blocks[key], moe=moe)
+    return dict(new_params, blocks=blocks)

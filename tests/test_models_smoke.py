@@ -78,6 +78,7 @@ def test_full_config_exact_shapes(arch):
         "qwen2-vl-7b": (28, 3584, 18944, 152064),
         "seamless-m4t-large-v2": (24, 1024, 8192, 256206),
         "recurrentgemma-2b": (26, 2560, 7680, 256000),
+        "moonlight-16b-a3b": (27, 2048, 1408, 163840),
     }
     L, d, ff, V = sheet[cfg.name]
     assert cfg.n_layers == L and cfg.d_model == d and cfg.vocab == V
@@ -90,6 +91,7 @@ def test_full_config_exact_shapes(arch):
     expected = {"grok-1-314b": 314e9, "phi3-medium-14b": 14e9,
                 "minicpm3-4b": 4e9, "qwen1.5-0.5b": 0.5e9,
                 "granite-8b": 8e9, "mamba2-1.3b": 1.3e9,
-                "recurrentgemma-2b": 2.7e9, "qwen2-vl-7b": 7e9}
+                "recurrentgemma-2b": 2.7e9, "qwen2-vl-7b": 7e9,
+                "moonlight-16b-a3b": 16e9}
     if cfg.name in expected:
         assert 0.5 <= n / expected[cfg.name] <= 1.7, (cfg.name, n)

@@ -126,3 +126,49 @@ def test_wordcount_emit_step_compiles_on_four_switches(mesh4):
                                sharding=NamedSharding(mesh4, P("all")))
     text = jax.jit(sharded).lower({f"s{i}": sds for i in range(4)}).compile().as_text()
     assert "collective-permute" in text
+
+
+def test_held_expert_layer_computes_only_routed_rows(one_chip, monkeypatch):
+    """Moonlight's expert layer at published widths, 8 of 64 experts held,
+    forward and backward: the held experts run as grouped-matmul kernels
+    over the buffer of routed rows (n · min(k, held), the kernel visiting
+    only the tiles the routed rows fill); no product takes every token
+    once per held expert."""
+    import dataclasses
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import moe as moe_mod
+    from repro.models.common import tree_specs_to_shapes
+    from repro.models.parallel import ShardEnv
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    base = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(base, moe=dataclasses.replace(base.moe, held=8))
+    env = ShardEnv(model_size=1, data_size=1, tp=1)
+    b, s, d, m = 4, 512, cfg.d_model, cfg.moe
+    n = b * s
+    rows = moe_mod.held_rows(n, m.top_k, m.n_held)
+    assert rows == n * min(m.top_k, m.n_held)
+
+    def loss(p, x):
+        y, aux, _ = moe_mod.moe_apply_held(p, x, cfg, env)
+        return jnp.sum(y.astype(jnp.float32) ** 2) + aux
+
+    shapes = tree_specs_to_shapes(moe_mod.moe_specs(cfg, env), jnp.float32)
+    p = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    x = jax.ShapeDtypeStruct((b, s, d), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile().as_text()
+    kernels = re.findall(r"= \w+\[(\d+),(\d+)\]\{[^}]*\} custom-call\([^\n]*"
+                         r'custom_call_target="tpu_custom_call"[^\n]*op_name="[^"]*experts[^"]*gmm',
+                         text)
+    assert kernels, "no grouped-matmul kernel in the experts scope"
+    assert {int(r) for r, _ in kernels} == {rows}
+    dense = re.findall(r"= \w+\[(\d+),(\d+)\][^\n]* (?:dot|convolution)\(", text)
+    widths = {m.d_expert, 2 * m.d_expert}
+    assert not [s for s in dense if int(s[0]) in (n, m.n_held * n) and int(s[1]) in widths]

@@ -52,9 +52,9 @@ def test_moe_a2a_matches_replicated(multidevice):
                  check_vma=False)
         def f(p, xx):
             if mode == "a2a":
-                y, aux = moe_mod.moe_apply_a2a(p["moe"], xx, cfg, env)
+                y, aux, _ = moe_mod.moe_apply_a2a(p["moe"], xx, cfg, env)
             else:
-                y, aux = moe_mod.moe_apply_replicated(p["moe"], xx, cfg, env)
+                y, aux, _ = moe_mod.moe_apply_replicated(p["moe"], xx, cfg, env)
             return y
         return np.asarray(f(params, jnp.asarray(x)))
 
